@@ -54,6 +54,8 @@ def main() -> None:
                             bench_kernels, bench_kmips, bench_load,
                             bench_params, bench_rkmips, bench_roofline,
                             bench_serving)
+    from repro import compile_cache
+    compile_cache.enable()
 
     small = args.scale == "smoke"
     suites = {

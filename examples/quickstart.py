@@ -16,7 +16,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import RkMIPSEngine, get_config
+from repro import RkMIPSEngine, compile_cache, get_config
 from repro.core import metrics
 from repro.data import synthetic
 
@@ -31,6 +31,7 @@ def main():
     ap.add_argument("--method", default="sah",
                     help="engine registry preset (sah, sa-simpfer, ...)")
     args = ap.parse_args()
+    compile_cache.enable()
 
     key = jax.random.PRNGKey(0)
     ki, kq, kb = jax.random.split(key, 3)

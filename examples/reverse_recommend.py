@@ -17,7 +17,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import RkMIPSEngine
+from repro import RkMIPSEngine, compile_cache
 from repro.configs import base as cfg_base
 from repro.core import metrics
 from repro.models import recsys as rec_lib
@@ -32,6 +32,7 @@ def main():
     ap.add_argument("--m-users", type=int, default=8192)
     ap.add_argument("--k", type=int, default=10)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = cfg_base.get("two-tower-retrieval").make_smoke_config()
     key = jax.random.PRNGKey(0)

@@ -31,7 +31,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import IndexArtifact, RkMIPSEngine, get_config
+from repro import IndexArtifact, RkMIPSEngine, compile_cache, get_config
 from repro.engine import RetrievalServer, TicketExpired
 from repro.data import synthetic
 
@@ -44,6 +44,7 @@ def main():
     ap.add_argument("--k", type=int, default=10)
     ap.add_argument("--queries", type=int, default=64)
     args = ap.parse_args()
+    compile_cache.enable()
 
     key = jax.random.PRNGKey(0)
     ki, kq, kb, kn = jax.random.split(key, 4)

@@ -30,7 +30,7 @@ import argparse
 import jax
 import numpy as np
 
-from repro import IndexArtifact, get_config
+from repro import IndexArtifact, compile_cache, get_config
 from repro.data import synthetic
 from repro.engine import ServingGateway, TenantPolicy
 
@@ -43,6 +43,7 @@ def main():
     ap.add_argument("--k", type=int, default=5)
     ap.add_argument("--queries", type=int, default=24)
     args = ap.parse_args()
+    compile_cache.enable()
 
     key = jax.random.PRNGKey(0)
     ki, kq, kb = jax.random.split(key, 3)

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import RkMIPSEngine, get_config
+from repro import RkMIPSEngine, compile_cache, get_config
 from repro.configs import base as cfg_base
 from repro.core import metrics
 from repro.kernels import ops as kops
@@ -37,6 +37,7 @@ def main():
     ap.add_argument("--requests", type=int, default=64)
     ap.add_argument("--k", type=int, default=20)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = cfg_base.get("two-tower-retrieval").make_smoke_config()
     key = jax.random.PRNGKey(0)

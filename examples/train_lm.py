@@ -12,6 +12,7 @@ import argparse
 import jax
 import jax.numpy as jnp
 
+from repro import compile_cache
 from repro.data import synthetic
 from repro.models import transformer as tf_lib
 from repro.train import checkpoint as ckpt_lib
@@ -41,6 +42,7 @@ def main():
     ap.add_argument("--fail-at", type=int, default=None)
     ap.add_argument("--grad-accum", type=int, default=1)
     args = ap.parse_args()
+    compile_cache.enable()
 
     cfg = MODELS[args.model]
     print(f"model={cfg.name} params~{cfg.n_params/1e6:.1f}M")

@@ -27,7 +27,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import IndexArtifact, RkMIPSEngine, get_config
+from repro import IndexArtifact, RkMIPSEngine, compile_cache, get_config
 from repro.data import synthetic
 
 
@@ -43,6 +43,7 @@ def main():
     ap.add_argument("--k", type=int, default=10)
     ap.add_argument("--inserts", type=int, default=24)
     args = ap.parse_args()
+    compile_cache.enable()
 
     key = jax.random.PRNGKey(0)
     ki, kq, kb, kn = jax.random.split(key, 4)

@@ -17,15 +17,6 @@ Layers:
 
 __version__ = "1.0.0"
 
-# Installing the dist compat aliases here (not only in repro.dist) means any
-# entry point -- launchers, benchmarks, subprocess test scripts -- sees the
-# modern jax.shard_map/jax.make_mesh API regardless of which submodule it
-# imports first. No device state is touched (see launch/mesh.py).
-from repro.dist import compat as _dist_compat
-
-_dist_compat.install()
-del _dist_compat
-
 # The engine registry is the package's front door (DESIGN.md SS7): every
 # paper baseline is a named preset config of one RkMIPSEngine. Re-exported
 # lazily (PEP 562): the engine pulls in repro.core, whose module-level jnp

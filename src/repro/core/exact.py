@@ -7,6 +7,10 @@ Tie/semantics convention (shared by every method in this repo):
   q is in the kMIPS result of u over P u {q}  <=>  #{p in P : <u,p> > <u,q>} <= k-1.
 Strictly-greater counting means ties resolve in favor of the query, matching
 the paper's Definition 1 where q itself is inserted into the item set.
+
+Every inner product runs at ``Precision.HIGHEST``: a TPU otherwise feeds f32
+matmuls to the MXU in reduced-precision passes, and the reference would no
+longer be exact there. On the CPU the results are unchanged.
 """
 
 from __future__ import annotations
@@ -14,12 +18,18 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _ips(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
+    """a (p, d) x b (r, d) -> (p, r) inner products at full f32 precision."""
+    return jnp.matmul(a, b.T, precision=_HIGHEST)
+
 
 def kmips(items: jnp.ndarray, queries: jnp.ndarray, k: int
           ) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Exact top-k MIPS. items (n,d), queries (q,d) -> (values, indices) (q,k)."""
-    ips = queries @ items.T
-    return jax.lax.top_k(ips, k)
+    return jax.lax.top_k(_ips(queries, items), k)
 
 
 def rkmips_decision(items: jnp.ndarray, users: jnp.ndarray,
@@ -34,8 +44,8 @@ def rkmips_decision(items: jnp.ndarray, users: jnp.ndarray,
     count; see tests/test_sah_engine.py). Use the same tie_eps in the engine.
     """
     eps = tie_eps * jnp.linalg.norm(query)
-    tau = users @ query                       # (m,)
-    ips = users @ items.T                     # (m, n)
+    tau = jnp.matmul(users, query, precision=_HIGHEST)   # (m,)
+    ips = _ips(users, items)                  # (m, n)
     beat = jnp.sum(ips > tau[:, None] + eps, axis=-1)
     return beat <= k - 1
 
@@ -45,8 +55,8 @@ def rkmips_batch(items: jnp.ndarray, users: jnp.ndarray,
                  tie_eps: float = 0.0) -> jnp.ndarray:
     """Exact RkMIPS for a batch of queries -> bool (q, m)."""
     eps = tie_eps * jnp.linalg.norm(queries, axis=-1)     # (q,)
-    tau = queries @ users.T                   # (q, m)
-    ips = users @ items.T                     # (m, n)
+    tau = _ips(queries, users)                # (q, m)
+    ips = _ips(users, items)                  # (m, n)
     beat = jnp.sum(ips[None, :, :] > tau[:, :, None] + eps[:, None, None],
                    axis=-1)
     return beat <= k - 1

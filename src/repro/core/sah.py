@@ -646,7 +646,7 @@ def rkmips_batch_impl(index: SAHIndex, queries: jnp.ndarray, k: int, *,
     (the jitted alias) directly; the impl exists so
     ``repro.engine.sharding`` can trace the raw body under ``shard_map`` --
     one flat while_loop, no nested jit and no scan-of-while, which is what
-    retires the jax 0.4.x per-query unroll workaround (the plan's lax.map
+    retires the per-query unroll workaround (the plan's lax.map
     contains only dense per-query math and is shard_map-safe).
     """
     if scan_precision != "int8":

@@ -1,9 +1,4 @@
-"""Distribution layer: sharding policies and jax API compat shims.
-
-The forward-compat aliases (jax.shard_map / jax.make_mesh on jax versions
-that predate them) are installed once by repro/__init__.py, which always
-runs before anything in this package imports. See DESIGN.md SS5.
-"""
+"""Distribution layer: sharding policies. See DESIGN.md SS5."""
 
 from repro.dist.policy import (
     NO_SHARDING,

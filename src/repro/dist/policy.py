@@ -34,7 +34,7 @@ import dataclasses
 from typing import Mapping
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 # Mesh axes that act as batch ("data-parallel") axes anywhere in the stack.
 # launch/mesh.py builds ("data", "model") and ("pod", "data", "model").
@@ -53,6 +53,19 @@ class ShardingPolicy:
 
     mesh: Mesh | None = None
     rules: Mapping[str, P] = dataclasses.field(default_factory=dict)
+
+    def __post_init__(self):
+        # ``jax.make_mesh`` types its axes Explicit, and eager ops that mix
+        # arrays committed to such a mesh with unsharded ones (the engine's
+        # host-side post-processing of sharded results) are rejected. The
+        # stack shards by PartitionSpec rules and shard_map, which is the
+        # Auto contract, so the policy holds the Auto view of the same
+        # devices (Mesh objects are interned: equal meshes stay identical).
+        if self.mesh is not None and any(
+                t != AxisType.Auto for t in self.mesh.axis_types):
+            object.__setattr__(self, "mesh", Mesh(
+                self.mesh.devices, self.mesh.axis_names,
+                axis_types=(AxisType.Auto,) * len(self.mesh.axis_names)))
 
     # -- rule lookup -------------------------------------------------------
 

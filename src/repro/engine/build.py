@@ -143,9 +143,9 @@ def row_parallel(fn, rows: jnp.ndarray, consts: tuple = (), *,
     so any row count runs on any mesh (the PR-3 convention).
 
     With a mesh policy: one eager ``shard_map`` over every mesh axis (an
-    outer jit around shard_map re-triggers the jax 0.4.x while-driver
-    miscompile; the bodies here are embarrassingly parallel, but the
-    engine-wide convention is eager dispatch). With ``shards``: the
+    outer jit around shard_map once miscompiled the while-driver, DESIGN.md
+    SS7; the bodies here are embarrassingly parallel, but the engine-wide
+    convention is eager dispatch). With ``shards``: the
     mesh-free simulation — per-slice compute + concatenate — used by the
     tests to pin the invariant in-process. Otherwise: ``fn`` unchanged.
     """
@@ -162,9 +162,9 @@ def row_parallel(fn, rows: jnp.ndarray, consts: tuple = (), *,
         # Gather to host layout before anything downstream touches the
         # result: the artifact contract is mesh-agnostic leaves, and eager
         # ops on an array still committed to the mesh run through implicit
-        # GSPMD partitioning, which on jax 0.4.x can miscompile (the same
-        # family as the outer-jit shard_map bug) — attach-time pad_index
-        # on a committed block_lb was observed to corrupt real entries.
+        # GSPMD partitioning, which was observed to miscompile (the same
+        # family as the outer-jit shard_map bug, DESIGN.md SS7) — attach-
+        # time pad_index on a committed block_lb corrupted real entries.
         return jnp.asarray(np.asarray(out)[:n])
     if shards is not None and shards > 1:
         n = rows.shape[0]

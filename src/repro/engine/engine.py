@@ -252,11 +252,11 @@ class RkMIPSEngine:
             # capacity is static, so corpus churn never retraces).
             # Single-device the counter increments at jit trace time
             # (ground truth); under a mesh the shard_map must dispatch
-            # eagerly — an *outer* jit staged around it re-triggers the
-            # jax 0.4.x while-driver miscompile (wrong predictions, caught
-            # by the sharded-equivalence test) — so there the counter keys
-            # on distinct dispatch signatures, which is exactly how the
-            # XLA executable cache keys its compiles.
+            # eagerly — an *outer* jit staged around it once miscompiled
+            # the while-driver (wrong predictions, caught by the
+            # sharded-equivalence test, DESIGN.md SS7) — so there the
+            # counter keys on distinct dispatch signatures, which is
+            # exactly how the XLA executable cache keys its compiles.
             self._traces = _TraceCount()
             self._rkmips_seen: set = set()
             if policy.mesh is None:

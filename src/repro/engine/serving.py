@@ -472,7 +472,7 @@ class RetrievalServer(_TicketQueue):
         retired — same shape, so the compiled dispatch is reused.
 
         Computed host-side (artifact ``deleted`` is host layout; eager ops
-        on mesh-committed arrays are the jax 0.4.x hazard engine/build.py
+        on mesh-committed arrays are the hazard engine/build.py
         documents) and memoized per bound (state, artifact): one O(n)
         pass per swap, zero per flush.
         """

@@ -17,7 +17,7 @@ parallel along one axis:
     loop over queries -- so it traces exactly once per batch shape at any
     batch size (pinned by the compile-count test) and is safe under
     ``shard_map`` where the old per-query drivers (nested jit / lax.map)
-    miscompiled on jax 0.4.x. Predictions are bitwise identical to the
+    miscompiled (DESIGN.md SS7). Predictions are bitwise identical to the
     unsharded run (asserted in tests/test_engine.py): queue compaction
     regroups lanes but each lane's decision is self-contained.
 
@@ -179,8 +179,8 @@ def rkmips_batch(index: _sah.SAHIndex, queries: jnp.ndarray, k: int,
     The shard_map body is the raw batched plan/execute driver on the
     shard's user slice: the plan's lax.map holds only dense per-query math
     and the execute phase is one flat while_loop, so — unlike the retired
-    per-query drivers (nested jit / scan-of-while, the jax 0.4.x
-    miscompile, DESIGN.md SS9) — the body traces once at any nq. The
+    per-query drivers (nested jit / scan-of-while, the miscompile of
+    DESIGN.md SS7, SS9) — the body traces once at any nq. The
     shard-local work queues are what make this load-balanced: a shard
     whose users die early for one query spends its chunks on the other
     queries' survivors instead of idling.

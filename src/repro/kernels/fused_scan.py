@@ -19,12 +19,16 @@ index on ties, which is exactly ``jax.lax.top_k``'s tie-break on negated
 distances, so the lax mirror below is candidate-for-candidate identical to
 the ref.py oracle. Selected lanes are masked to INT32_MAX; unselected
 entries are at most _BIG_HAMMING (1 << 30) < INT32_MAX, so a row can never
-be picked twice while any unpicked row remains.
+be picked twice while any unpicked row remains. The kernel spells the same
+argmin as two f32 min-reductions (the minimum, then the lowest column
+holding it), with +inf as the picked mask, and gathers the picked int8 row
+by a one-hot bf16 matmul -- exact, since both operands are small integers.
 
 Tiling: grid (C // block_q,). Each program instance owns ``block_q`` user
-lanes and the whole (T, W) code tile / (T, d) int8 tile -- T is the core
-library's partition tile (<= 4096), so at T=4096, d=128, W=8 the resident
-VMEM is 4096*8*4 + 4096*128 + 4096*4 + block_q*(W*4 + d*4) ~ 0.7 MB.
+lanes and the whole (W, T) code tile (transposed, items on the lanes as in
+kernels/hamming_scan.py) / (T, d) int8 tile -- T is the core library's
+partition tile (<= 4096), so at T=4096, d=128, W=8 the resident VMEM is
+4096*8*4 + 4096*128 + 4096*4 + block_q*(W*4 + d*4) ~ 0.7 MB.
 """
 
 from __future__ import annotations
@@ -35,6 +39,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import hamming_scan as _hamming
 from repro.kernels import ref as _ref
 
 # Python ints, not jnp scalars: the Pallas kernel body may not capture
@@ -78,34 +83,41 @@ def fused_scan_lax(ucodes: jnp.ndarray, item_codes: jnp.ndarray,
 
 def _fused_scan_kernel(uc_ref, codes_ref, mask_ref, qitems_ref, qscale_ref,
                        users_ref, cand_ref, qips_ref, *, n_cand):
-    uc = uc_ref[...]                     # (bq, W) uint32
-    codes = codes_ref[...]               # (T, W) uint32
-    mask = mask_ref[...]                 # (1, T) int32
-    qf = qitems_ref[...].astype(jnp.float32)   # (T, d)
+    # int8 rows are small integers: exact in bf16, so the one-hot gather
+    # below is an exact single-pass MXU matmul
+    qi = qitems_ref[...].astype(jnp.float32).astype(jnp.bfloat16)  # (T, d)
     qs = qscale_ref[...]                 # (1, T) f32
     u = users_ref[...]                   # (bq, d) f32
-    bq, t = uc.shape[0], codes.shape[0]
+    dist = _hamming.distances(uc_ref[...], codes_ref[...])        # (bq, T)
+    # distances are integers < 2^24, exact in f32: the selection runs as
+    # f32 min-reductions, the only reductions Mosaic lowers for it
+    dist = jnp.where(mask_ref[...] > 0, dist,
+                     _BIG_HAMMING).astype(jnp.float32)
+    bq, t = dist.shape
+    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, t), 1).astype(jnp.float32)
+    slots = jax.lax.broadcasted_iota(jnp.int32, (bq, n_cand), 1)
 
-    x = jnp.bitwise_xor(uc[:, None, :], codes[None, :, :])
-    dist = jnp.sum(jax.lax.population_count(x), axis=-1).astype(jnp.int32)
-    dist = jnp.where(mask > 0, dist, _BIG_HAMMING)        # (bq, T)
+    def pick(i, state):
+        d_, cand, qips = state
+        best = jnp.min(d_, axis=-1, keepdims=True)               # (bq, 1)
+        # lowest column among the minima: lax.top_k's tie-break
+        arg = jnp.min(jnp.where(d_ == best, cols, float(t)), axis=-1,
+                      keepdims=True)                             # (bq, 1)
+        onehot = cols == arg                                     # (bq, T)
+        row = jnp.dot(onehot.astype(jnp.bfloat16), qi,
+                      preferred_element_type=jnp.float32)        # (bq, d)
+        scale = jnp.sum(jnp.where(onehot, qs, 0.0), axis=-1, keepdims=True)
+        ip = jnp.sum(row * u, axis=-1, keepdims=True) * scale
+        # candidates accumulate in registers by select; one store at the end
+        slot = slots == i
+        cand = jnp.where(slot, arg, cand)
+        qips = jnp.where(slot, ip, qips)
+        return jnp.where(onehot, jnp.inf, d_), cand, qips
 
-    cols = jax.lax.broadcasted_iota(jnp.int32, (bq, t), 1)
-
-    def pick(i, d_):
-        arg = jnp.argmin(d_, axis=-1).astype(jnp.int32)   # (bq,)
-        onehot = cols == arg[:, None]                     # (bq, T)
-        # dynamic row gather as a one-hot matmul: MXU-friendly, no
-        # per-lane scatter/gather addressing inside the kernel
-        row = jnp.dot(onehot.astype(jnp.float32), qf,
-                      preferred_element_type=jnp.float32)  # (bq, d)
-        scale = jnp.sum(jnp.where(onehot, qs, 0.0), axis=-1)
-        ip = jnp.sum(row * u, axis=-1) * scale
-        cand_ref[:, i] = arg
-        qips_ref[:, i] = ip
-        return jnp.where(onehot, _INT_MAX, d_)
-
-    jax.lax.fori_loop(0, n_cand, pick, dist)
+    zeros = jnp.zeros((bq, n_cand), jnp.float32)
+    _, cand, qips = jax.lax.fori_loop(0, n_cand, pick, (dist, zeros, zeros))
+    cand_ref[...] = cand.astype(jnp.int32)
+    qips_ref[...] = qips
 
 
 @functools.partial(jax.jit,
@@ -120,9 +132,9 @@ def fused_scan_tiles(ucodes: jnp.ndarray, item_codes: jnp.ndarray,
     qitems (T, d) int8, qscale (T,) f32, users (C, d) f32
     -> (cand (C, n_cand) int32, qips (C, n_cand) f32).
 
-    C must be a multiple of block_q (ops.py falls back to block_q=1).
+    C must be a multiple of block_q (kernels/ops.py pads).
     cand matches ref.fused_scan exactly; qips matches to float tolerance
-    (the one-hot matmul gather reassociates the dot product).
+    (the in-kernel dot product reassociates the oracle's einsum).
     """
     c, w = ucodes.shape
     t, w2 = item_codes.shape
@@ -137,7 +149,7 @@ def fused_scan_tiles(ucodes: jnp.ndarray, item_codes: jnp.ndarray,
         grid=grid,
         in_specs=[
             pl.BlockSpec((block_q, w), lambda i: (i, 0)),
-            pl.BlockSpec((t, w), lambda i: (0, 0)),
+            pl.BlockSpec((w, t), lambda i: (0, 0)),
             pl.BlockSpec((1, t), lambda i: (0, 0)),
             pl.BlockSpec((t, d), lambda i: (0, 0)),
             pl.BlockSpec((1, t), lambda i: (0, 0)),
@@ -152,4 +164,6 @@ def fused_scan_tiles(ucodes: jnp.ndarray, item_codes: jnp.ndarray,
             jax.ShapeDtypeStruct((c, n_cand), jnp.float32),
         ],
         interpret=interpret,
-    )(ucodes, item_codes, mask2, qitems, qscale2, users)
+        name="fused_scan",
+    )(_hamming.as_words(ucodes), _hamming.as_words(item_codes).T, mask2,
+      qitems, qscale2, users)

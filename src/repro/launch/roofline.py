@@ -114,17 +114,8 @@ class Roofline:
         }
 
 
-def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() as a flat dict across jax versions (jax
-    0.4.x returns a one-dict-per-program list, newer jax the dict itself)."""
-    cost = compiled.cost_analysis()
-    if isinstance(cost, (list, tuple)):
-        cost = cost[0] if cost else {}
-    return cost
-
-
 def from_compiled(compiled) -> Roofline:
-    cost = cost_dict(compiled)
+    cost = compiled.cost_analysis()
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     return Roofline(

@@ -18,7 +18,6 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from repro.dist import compat as dist_compat
 from repro.dist.policy import NO_SHARDING, ShardingPolicy
 from repro.models import attention as attn
 from repro.models import moe as moe_lib
@@ -250,9 +249,8 @@ def forward(params, tokens: jnp.ndarray, cfg: LMConfig,
 
     def body(x, lp):
         # barrier: stops XLA folding the rms-norm f32 upcast into the
-        # scan-saved carry buffer (which would store residuals at 2x bytes);
-        # the compat wrapper keeps it differentiable on jax 0.4.x
-        x = dist_compat.optimization_barrier(x)
+        # scan-saved carry buffer (which would store residuals at 2x bytes)
+        x = jax.lax.optimization_barrier(x)
         x2, aux, kv = _layer(x, lp, cfg, policy, positions)
         return x2, (aux, kv if return_cache else None)
 

@@ -283,7 +283,7 @@ for method in ("sah", "simpfer"):
 
 # The sharded path contains no Python-level loop over queries: one trace of
 # the batched plan/execute body per shard_map dispatch, at any batch size
-# (the jax 0.4.x per-query unroll is retired, DESIGN.md SS9).
+# (the per-query unroll is retired, DESIGN.md SS9).
 from repro.core import sah as sah_mod
 cfg = get_config("sah").replace(tile=128, n_bits=64)
 e1 = RkMIPSEngine(cfg, policy=policy).build(items, users, kb)
