@@ -511,6 +511,7 @@ def merge_topk(vals: jnp.ndarray, ids: jnp.ndarray,
     return best, jnp.take_along_axis(merged_i, pos, axis=-1)
 
 
+@jax.named_scope("kmips.merge")
 def merge_delta_topk(vals: jnp.ndarray, ids: jnp.ndarray,
                      queries: jnp.ndarray, d_items: jnp.ndarray,
                      d_mask: jnp.ndarray, k: int, n_base: int, *,

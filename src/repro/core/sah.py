@@ -453,6 +453,7 @@ class RkMIPSPlan(NamedTuple):
     n_scan: jnp.ndarray
 
 
+@jax.named_scope("sah.plan")
 def rkmips_plan_impl(index: SAHIndex, queries: jnp.ndarray, k: int, *,
                      tie_eps: float = 0.0,
                      delta_items: jnp.ndarray | None = None,
@@ -523,6 +524,7 @@ rkmips_plan = functools.partial(
     jax.jit, static_argnames=("k", "tie_eps"))(rkmips_plan_impl)
 
 
+@jax.named_scope("sah.execute")
 def rkmips_execute_impl(index: SAHIndex, plan: RkMIPSPlan, k: int, *,
                         n_cand: int = 64, scan: str = "sketch",
                         chunk: int = 256, scan_precision: str = "f32",

@@ -93,7 +93,8 @@ class GatewayStats(NamedTuple):
     tenants:              per-tenant ``RuntimeStats`` — counters are
                           attributed to the tenant whose runtime did the
                           work, never pooled (stats isolation is pinned
-                          by tests/test_gateway.py).
+                          by tests/test_gateway.py); queue and linger
+                          waits (``queue_wait_s``, ``linger_s``) too.
     traces_after_warmup:  gateway-wide traces since ``warmup()``, summed
                           over *distinct* share groups (a trace a shared
                           dispatch cost is counted once, not once per
@@ -118,7 +119,8 @@ class ServingGateway:
     Parameters:
       pool_workers   dispatch threads shared by every tenant.
       poll_interval  pool idle wakeup (seconds); bounds pooled linger
-                     latency.
+                     latency (each tenant's ``RuntimeStats.linger_s``
+                     measures what the linger really held).
     """
 
     def __init__(self, *, pool_workers: int = 1,

@@ -65,6 +65,17 @@ interrupted; XLA dispatches are not cancellable), so one stalled consumer
 or a deep backlog can't wedge every later ticket behind work nobody
 wants. Per-batch, expiry costs one clock read.
 
+Tracing: each stage a ticket crosses is a ``jax.profiler.TraceAnnotation``
+named ``rk.*`` — ``rk.submit`` (validation + enqueue), ``rk.form`` (batch
+formation), ``rk.flush`` (one dispatch, with the server's ``rk.flush.*``
+sub-spans nested inside), ``rk.resolve`` (the completion loop resolving
+one batch) — each carrying the batch's first admission ``seq`` (``seq0``)
+and its size ``n``, so one batch's spans line up across threads in a
+profile. With the profiler off an annotation costs its construction and
+nothing else: no string is formatted and no device value is read. The
+waits that cross threads are counters instead (``RuntimeStats.queue_wait_s``
+and ``linger_s``), readable without a profiler.
+
 ``drain()`` blocks until every admitted ticket has resolved; ``close()``
 drains (optional), stops the threads, and fails whatever is left —
 afterwards ``submit`` raises. The runtime is a context manager.
@@ -77,6 +88,8 @@ import queue as _queue
 import threading
 import time
 from typing import NamedTuple
+
+from jax.profiler import TraceAnnotation
 
 from repro.engine import artifact as _artifact
 from repro.engine import serving as _serving
@@ -99,10 +112,17 @@ class ServeTicket:
     the admission sequence number (tickets dispatch in ``seq`` order per
     signature run, and results never cross tickets — pinned by
     tests/test_runtime.py).
+
+    Host stamps (``time.perf_counter()``): ``submitted_at`` at admission,
+    ``formed_at`` when batch formation pops the ticket for dispatch (None
+    if it expired or never left the queue), ``done_at`` at resolution.
+    ``formed_at - submitted_at`` is the ticket's queue wait, linger
+    included; ``done_at - formed_at`` its dispatch and completion.
     """
 
     __slots__ = ("query", "k", "n_cand", "scan", "seq", "deadline",
-                 "submitted_at", "done_at", "_event", "_value", "_error")
+                 "submitted_at", "formed_at", "done_at", "_event", "_value",
+                 "_error")
 
     def __init__(self, query, k: int, n_cand, scan, seq: int,
                  deadline: float | None):
@@ -113,6 +133,7 @@ class ServeTicket:
         self.seq = seq
         self.deadline = deadline          # absolute monotonic time or None
         self.submitted_at = time.perf_counter()
+        self.formed_at: float | None = None
         self.done_at: float | None = None
         self._event = threading.Event()
         self._value = None
@@ -174,7 +195,17 @@ class RuntimeStats(NamedTuple):
     (``EngineConfig.scan_budget``) resolved conservatively — the
     per-ticket ``ReverseResult.truncated`` flag aggregated per runtime,
     so budget pressure is attributable per tenant (DESIGN.md SS15),
-    never silent."""
+    never silent.
+
+    The last two are host seconds, summed and monotone, for the waits
+    that start on one thread and end on another (a profiler span cannot
+    cover those): ``queue_wait_s`` is, over every ticket popped for
+    dispatch, ``formed_at - submitted_at`` (``ServeTicket``); ``linger_s``
+    is, over every batch, how long a partial head batch was held for a
+    fuller one, from the first moment a worker could have dispatched it
+    to its formation — with a ``WorkerPool`` that includes the pool's
+    wake-up, which can outlast ``batch_linger``. Over a window,
+    ``Δqueue_wait_s / Δcompleted`` is the mean queue wait per ticket."""
 
     submitted: int
     completed: int
@@ -187,6 +218,8 @@ class RuntimeStats(NamedTuple):
     bucket_pad_rows: int  # dead rows added by bucket padding
     traces_after_warmup: int  # server traces since the warmup baseline
     truncated: int    # tickets answered under an exhausted scan budget
+    queue_wait_s: float   # sum of formed_at - submitted_at, popped tickets
+    linger_s: float       # sum of partial-batch hold time, per batch
 
 
 class WorkerPool:
@@ -396,8 +429,12 @@ class ServingRuntime:
         self._bucket_hits = 0
         self._bucket_pad_rows = 0
         self._truncated = 0
+        self._queue_wait_s = 0.0
+        self._linger_s = 0.0
         self._pool = pool
-        self._linger_until: float | None = None   # pooled-linger deadline
+        # when a worker first held the current partial head batch for a
+        # fuller one (perf_counter); None while nothing lingers
+        self._linger_since: float | None = None
         self.last_compaction_seconds: float | None = None
 
         # AOT warmup runs before any worker exists, so no ticket can race
@@ -455,33 +492,37 @@ class ServingRuntime:
         fragmentation, not correctness). Raises ``RuntimeError`` once the
         runtime is closed.
         """
-        q = _serving.validate_query_rows(q, self.server._dim,
-                                         "runtime.submit")
-        k = self._default_k if k is None else k
-        if k is None:
-            raise ValueError("no k for this ticket: pass submit(..., k=) "
-                             "or construct ServingRuntime(..., k=)")
-        if self._is_reverse and (n_cand is not None or scan is not None):
-            raise ValueError("n_cand/scan are forward-serving knobs; the "
-                             "reverse pipeline has no per-ticket override")
-        budget = self._default_deadline if deadline is _UNSET else deadline
-        expiry = None if budget is None else time.monotonic() + budget
-        rows = [q] if q.ndim == 1 else [q[i] for i in range(q.shape[0])]
-        with self._admit:
-            if self._closed:
-                raise RuntimeError("runtime is closed: no new tickets "
-                                   "(create a new ServingRuntime)")
-            tickets = []
-            for row in rows:
-                t = ServeTicket(row, k, n_cand, scan, self._seq, expiry)
-                self._seq += 1
-                self._ticket_deque.append(t)
-                tickets.append(t)
-            self._submitted += len(tickets)
-            self._unfinished += len(tickets)
-            self._admit.notify_all()
-        if self._pool is not None:
-            self._pool.notify()
+        with TraceAnnotation("rk.submit") as span:
+            q = _serving.validate_query_rows(q, self.server._dim,
+                                             "runtime.submit")
+            k = self._default_k if k is None else k
+            if k is None:
+                raise ValueError("no k for this ticket: pass submit(..., "
+                                 "k=) or construct ServingRuntime(..., k=)")
+            if self._is_reverse and (n_cand is not None or scan is not None):
+                raise ValueError("n_cand/scan are forward-serving knobs; the "
+                                 "reverse pipeline has no per-ticket "
+                                 "override")
+            budget = (self._default_deadline if deadline is _UNSET
+                      else deadline)
+            expiry = None if budget is None else time.monotonic() + budget
+            rows = [q] if q.ndim == 1 else [q[i] for i in range(q.shape[0])]
+            with self._admit:
+                if self._closed:
+                    raise RuntimeError("runtime is closed: no new tickets "
+                                       "(create a new ServingRuntime)")
+                tickets = []
+                for row in rows:
+                    t = ServeTicket(row, k, n_cand, scan, self._seq, expiry)
+                    self._seq += 1
+                    self._ticket_deque.append(t)
+                    tickets.append(t)
+                self._submitted += len(tickets)
+                self._unfinished += len(tickets)
+                self._admit.notify_all()
+            if self._pool is not None:
+                self._pool.notify()
+            span.set_metadata(seq0=tickets[0].seq, n=len(tickets))
         return tickets[0] if q.ndim == 1 else tickets
 
     # -- worker / completion loops -----------------------------------------
@@ -502,23 +543,35 @@ class ServingRuntime:
         queue-head tickets sharing one signature, up to
         ``serve_batch_size``. Expired tickets are failed here,
         pre-dispatch. Caller holds ``_admit``. [] = nothing poppable."""
-        size = self.server.batch_size
-        batch: list[ServeTicket] = []
-        sig = None
-        now = time.monotonic()
-        while self._ticket_deque and len(batch) < size:
-            head = self._ticket_deque[0]
-            if head.deadline is not None and now >= head.deadline:
-                self._ticket_deque.popleft()
-                self._completion.put(([head], None, TicketExpired(
-                    f"ticket {head.seq} missed its deadline "
-                    f"before dispatch"), None))
-                continue
-            if sig is None:
-                sig = self._signature(head)
-            elif self._signature(head) != sig:
-                break
-            batch.append(self._ticket_deque.popleft())
+        with TraceAnnotation("rk.form") as span:
+            size = self.server.batch_size
+            batch: list[ServeTicket] = []
+            sig = None
+            now = time.monotonic()
+            while self._ticket_deque and len(batch) < size:
+                head = self._ticket_deque[0]
+                if head.deadline is not None and now >= head.deadline:
+                    self._ticket_deque.popleft()
+                    self._completion.put(([head], None, TicketExpired(
+                        f"ticket {head.seq} missed its deadline "
+                        f"before dispatch"), None))
+                    continue
+                if sig is None:
+                    sig = self._signature(head)
+                elif self._signature(head) != sig:
+                    break
+                batch.append(self._ticket_deque.popleft())
+            # an empty batch means every head ticket expired: the queue
+            # is empty, so nothing lingers either way
+            formed = time.perf_counter()
+            if batch and self._linger_since is not None:
+                self._linger_s += formed - self._linger_since
+            self._linger_since = None
+            for t in batch:
+                t.formed_at = formed
+                self._queue_wait_s += formed - t.submitted_at
+            if batch:
+                span.set_metadata(seq0=batch[0].seq, n=len(batch))
         return batch
 
     def _next_batch(self) -> list[ServeTicket] | None:
@@ -543,6 +596,8 @@ class ServingRuntime:
                     # wait: it dispatches with zero padding, so lingering
                     # buys throughput nothing and costs latency.
                     lingered = True
+                    if self._linger_since is None:
+                        self._linger_since = time.perf_counter()
                     self._admit.wait(self._linger)
                     continue
                 batch = self._form_batch()
@@ -554,25 +609,25 @@ class ServingRuntime:
         """Non-blocking batch formation for pooled workers (the caller —
         a ``WorkerPool`` thread — already holds this runtime's dispatch
         lock). Returns None when the queue is empty or still lingering
-        for a fuller batch; the linger is a deadline (``_linger_until``)
+        for a fuller batch; the linger is a deadline (``_linger_since`` +
+        ``batch_linger``)
         rather than a sleep, so a pool thread never blocks on one tenant
         while others have work."""
         with self._admit:
             n = len(self._ticket_deque)
             if n == 0:
-                self._linger_until = None
+                self._linger_since = None
                 return None
             if (self._linger > 0
                     and n < self.server.batch_size
                     and n not in self._ladder()
                     and not self._stop.is_set()):
-                now = time.monotonic()
-                if self._linger_until is None:
-                    self._linger_until = now + self._linger
+                now = time.perf_counter()
+                if self._linger_since is None:
+                    self._linger_since = now
                     return None
-                if now < self._linger_until:
+                if now < self._linger_since + self._linger:
                     return None
-            self._linger_until = None
             return self._form_batch() or None
 
     def _dispatch_batch(self, batch: list[ServeTicket]) -> tuple[list, int]:
@@ -584,13 +639,15 @@ class ServingRuntime:
         first = batch[0]
         group = [t.query for t in batch]
         pad_to = self.server.bucket_for(len(group))
-        if self._is_reverse:
+        with TraceAnnotation("rk.flush", seq0=first.seq, n=len(group),
+                             pad_to=pad_to):
+            if self._is_reverse:
+                return (self.server._flush_batch(group, first.k,
+                                                 pad_to=pad_to), pad_to)
             return (self.server._flush_batch(group, first.k,
+                                             n_cand=first.n_cand,
+                                             scan=first.scan,
                                              pad_to=pad_to), pad_to)
-        return (self.server._flush_batch(group, first.k,
-                                         n_cand=first.n_cand,
-                                         scan=first.scan,
-                                         pad_to=pad_to), pad_to)
 
     def _worker_loop(self) -> None:
         while True:
@@ -611,29 +668,31 @@ class ServingRuntime:
             if item is _SHUTDOWN:
                 return
             batch, results, error, pad_to = item
-            if error is not None:
-                for t in batch:
-                    t._resolve(error=error)
-            else:
-                for t, r in zip(batch, results):
-                    t._resolve(value=r)
-            with self._admit:
-                self._unfinished -= len(batch)
-                if error is None:
-                    self._completed += len(batch)
-                    self._batches += 1
-                    self._truncated += sum(
-                        1 for r in results
-                        if getattr(r, "truncated", False))
-                    if pad_to is not None:
-                        if pad_to < self.server.batch_size:
-                            self._bucket_hits += 1
-                        self._bucket_pad_rows += pad_to - len(batch)
-                elif isinstance(error, TicketExpired):
-                    self._expired += len(batch)
+            with TraceAnnotation("rk.resolve", seq0=batch[0].seq,
+                                 n=len(batch)):
+                if error is not None:
+                    for t in batch:
+                        t._resolve(error=error)
                 else:
-                    self._failed += len(batch)
-                self._admit.notify_all()
+                    for t, r in zip(batch, results):
+                        t._resolve(value=r)
+                with self._admit:
+                    self._unfinished -= len(batch)
+                    if error is None:
+                        self._completed += len(batch)
+                        self._batches += 1
+                        self._truncated += sum(
+                            1 for r in results
+                            if getattr(r, "truncated", False))
+                        if pad_to is not None:
+                            if pad_to < self.server.batch_size:
+                                self._bucket_hits += 1
+                            self._bucket_pad_rows += pad_to - len(batch)
+                    elif isinstance(error, TicketExpired):
+                        self._expired += len(batch)
+                    else:
+                        self._failed += len(batch)
+                    self._admit.notify_all()
 
     # -- artifact lifecycle ------------------------------------------------
 
@@ -755,7 +814,8 @@ class ServingRuntime:
                                 self._expired, self._failed, self._batches,
                                 self._swaps, self._compactions,
                                 self._bucket_hits, self._bucket_pad_rows,
-                                traces, self._truncated)
+                                traces, self._truncated, self._queue_wait_s,
+                                self._linger_s)
 
     @property
     def pending(self) -> int:

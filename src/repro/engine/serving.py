@@ -52,16 +52,28 @@ whether a query is served alone, inside any micro-batch, or in a one-shot
 batch — flat-scan rows and RkMIPS work-queue lanes are both independent
 and padding is dead, so batching is a latency/throughput knob, never an
 accuracy knob.
+
+Tracing: ``_flush_batch`` of both servers opens the host spans
+``rk.flush.pad`` (stack and pad), ``rk.flush.launch`` (the compiled
+dispatch, or the engine call), ``rk.flush.merge`` (the delta fold-in, only
+with staged rows) and ``rk.flush.split`` (per-ticket results) as
+``jax.profiler.TraceAnnotation``s, nested in the runtime's ``rk.flush``.
+On the device, the forward stages run under ``jax.named_scope``s
+(``kmips.hash``, ``.scan``, ``.select``, ``.rerank``, ``.merge``), and
+``op_scopes`` maps the compiled instructions to them.
 """
 
 from __future__ import annotations
 
+import re
+import threading
 from collections import OrderedDict
 from typing import NamedTuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from repro.core import sa_alsh as _alsh
@@ -71,6 +83,59 @@ from repro.engine.artifact import IndexArtifact, corpus_fingerprint
 from repro.engine.config import EngineConfig, get_config
 from repro.engine.engine import _TraceCount
 from repro.kernels import ops as kops
+
+
+_SCOPE_PREFIX = "kmips."
+_HLO_MODULE = re.compile(r"^HloModule ([^\s,]+)", re.M)
+_HLO_INSTR = re.compile(r"^\s*(?:ROOT )?%([^\s=]+) = (.*)$", re.M)
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+# (HLO module name, instruction name) -> every scope ("" = none) it had in
+# an executable RetrievalServer.warmup compiled in this process
+_SCOPES_SEEN: dict[tuple[str, str], set[str]] = {}
+_SCOPES_LOCK = threading.Lock()
+
+
+def instruction_scopes(hlo_text: str) -> tuple[str, dict[str, str]]:
+    """One compiled HLO module's text (``compiled.as_text()``) -> (its
+    module name, {instruction name: the innermost ``kmips.*`` named scope
+    of the instruction's ``op_name`` metadata, ``""`` when it has none})."""
+    module = _HLO_MODULE.search(hlo_text)
+    scopes = {}
+    for name, rest in _HLO_INSTR.findall(hlo_text):
+        op = _OP_NAME.search(rest)
+        path = op.group(1).split("/") if op else ()
+        inner = [part for part in path if part.startswith(_SCOPE_PREFIX)]
+        scopes[name] = inner[-1] if inner else ""
+    return (module.group(1) if module else ""), scopes
+
+
+def _record_scopes(compiled) -> None:
+    module, scopes = instruction_scopes(compiled.as_text())
+    if not any(scopes.values()):
+        # JAX's persistent cache keys leave metadata out, so an entry
+        # compiled before these scopes existed comes back without them
+        return
+    with _SCOPES_LOCK:
+        for name, scope in scopes.items():
+            _SCOPES_SEEN.setdefault((module, name), set()).add(scope)
+
+
+def op_scopes() -> dict:
+    """Which forward stage each device instruction runs, over every
+    executable ``RetrievalServer.warmup`` has compiled in this process:
+    {(HLO module name, instruction name): ``kmips.*`` scope}.
+
+    A device profile names each op by its instruction (``%sort.10 =
+    ...``) inside a module launch (``jit__scan(...)``) and carries no
+    scope, so this map is what attributes device time to the stages
+    ``kmips.hash``, ``.scan``, ``.select``, ``.rerank`` and ``.merge``.
+    An instruction that executables of one module name (the ladder's
+    rungs) place in two scopes maps to None — ambiguous, to be left
+    unattributed, never guessed; instructions outside every scope are
+    left out."""
+    with _SCOPES_LOCK:
+        return {key: next(iter(seen)) if len(seen) == 1 else None
+                for key, seen in _SCOPES_SEEN.items() if seen != {""}}
 
 
 class ServingState(NamedTuple):
@@ -401,7 +466,8 @@ class RetrievalServer(_TicketQueue):
             # Traced once per static signature; the counter increments at
             # trace time only, so it counts compiles, not calls.
             self._traces.n += 1
-            ucodes = kops.srp_hash(queries, proj_q)
+            with jax.named_scope("kmips.hash"):
+                ucodes = kops.srp_hash(queries, proj_q)
             return _sharding.kmips_flat_arrays(
                 items_a, ids_a, mask_a, codes_a, ucodes, queries, k,
                 self.policy, n_cand=n_cand, scan=scan)
@@ -554,22 +620,27 @@ class RetrievalServer(_TicketQueue):
         if len(group) > batch:
             raise ValueError(f"group of {len(group)} does not fit "
                              f"pad_to={batch}")
-        qs = jnp.stack(group)
-        if len(group) < batch:
-            qs = jnp.concatenate(
-                [qs, jnp.zeros((batch - len(group), qs.shape[1]),
-                               qs.dtype)])
-        vals, ids = self._dispatch(state.items, state.item_ids,
-                                   self._masked_item_mask(state),
-                                   state.codes, state.proj_q, qs, k=k,
-                                   n_cand=n_cand, scan=scan)
+        with TraceAnnotation("rk.flush.pad"):
+            qs = jnp.stack(group)
+            if len(group) < batch:
+                qs = jnp.concatenate(
+                    [qs, jnp.zeros((batch - len(group), qs.shape[1]),
+                                   qs.dtype)])
+        with TraceAnnotation("rk.flush.launch"):
+            vals, ids = self._dispatch(state.items, state.item_ids,
+                                       self._masked_item_mask(state),
+                                       state.codes, state.proj_q, qs, k=k,
+                                       n_cand=n_cand, scan=scan)
         d_items, d_mask, d_qitems, d_qscale = self._delta
         if d_items is not None:
-            vals, ids = self._merge(
-                vals, ids, qs, d_items, d_mask, d_qitems, d_qscale, k=k,
-                n_base=self.artifact.n_base,
-                scan_precision=self.config.scan_precision)
-        return [ServeResult(vals[j], ids[j], k) for j in range(len(group))]
+            with TraceAnnotation("rk.flush.merge"):
+                vals, ids = self._merge(
+                    vals, ids, qs, d_items, d_mask, d_qitems, d_qscale, k=k,
+                    n_base=self.artifact.n_base,
+                    scan_precision=self.config.scan_precision)
+        with TraceAnnotation("rk.flush.split"):
+            return [ServeResult(vals[j], ids[j], k)
+                    for j in range(len(group))]
 
     def warmup(self, ks, *, n_cands=None, scans=None,
                buckets=None) -> int:
@@ -586,6 +657,8 @@ class RetrievalServer(_TicketQueue):
         of cells compiled. Lowering traces the same jitted callables the
         live path calls (``compile_count`` counts these warmup traces
         too), and the populated jit cache is what the live calls hit.
+        Each compiled cell's instruction scopes are recorded for
+        ``op_scopes``.
         """
         state = self.cache.get(self.config)
         mask = self._masked_item_mask(state)
@@ -607,20 +680,20 @@ class RetrievalServer(_TicketQueue):
             for k in ks:
                 for nc in n_cands:
                     for sc in scans:
-                        self._dispatch.lower(
+                        _record_scopes(self._dispatch.lower(
                             state.items, state.item_ids, mask,
                             state.codes, state.proj_q, qs, k=k,
-                            n_cand=nc, scan=sc).compile()
+                            n_cand=nc, scan=sc).compile())
                         cells += 1
                 if art is not None:
                     vals = jnp.zeros((b, k), state.items.dtype)
                     ids = jnp.zeros((b, k), state.item_ids.dtype)
-                    self._merge.lower(
+                    _record_scopes(self._merge.lower(
                         vals, ids, qs, art.delta_items, art.delta_mask,
                         art.delta_qitems, art.delta_qscale, k=k,
                         n_base=art.n_base,
                         scan_precision=self.config.scan_precision
-                    ).compile()
+                    ).compile())
                     cells += 1
         return cells
 
@@ -777,23 +850,27 @@ class ReverseServer(_TicketQueue):
         if len(group) > batch:
             raise ValueError(f"group of {len(group)} does not fit "
                              f"pad_to={batch}")
-        qs = jnp.stack(group)
-        if len(group) < batch:
-            qs = jnp.concatenate(
-                [qs, jnp.broadcast_to(qs[:1], (batch - len(group),)
-                                      + qs.shape[1:])])
-        res = self.engine.query_batch(qs, k)
-        # Per-ticket truncation flag: the stats row carries 1 iff a scan
-        # budget skipped lanes of THAT query (core/sah.py trunc_q); the
-        # funnel snapshot rides along so truncation is never silent.
-        trunc = np.asarray(res.stats.truncated)
-        return [
-            ReverseResult(res.predictions[j],
-                          jax.tree.map(lambda s, j=j: s[j], res.stats),
-                          k,
-                          truncated=bool(trunc[j] > 0),
-                          funnel=res.funnel)
-            for j in range(len(group))]
+        with TraceAnnotation("rk.flush.pad"):
+            qs = jnp.stack(group)
+            if len(group) < batch:
+                qs = jnp.concatenate(
+                    [qs, jnp.broadcast_to(qs[:1], (batch - len(group),)
+                                          + qs.shape[1:])])
+        with TraceAnnotation("rk.flush.launch"):
+            res = self.engine.query_batch(qs, k)
+        with TraceAnnotation("rk.flush.split"):
+            # Per-ticket truncation flag: the stats row carries 1 iff a
+            # scan budget skipped lanes of THAT query (core/sah.py
+            # trunc_q); the funnel snapshot rides along so truncation is
+            # never silent.
+            trunc = np.asarray(res.stats.truncated)
+            return [
+                ReverseResult(res.predictions[j],
+                              jax.tree.map(lambda s, j=j: s[j], res.stats),
+                              k,
+                              truncated=bool(trunc[j] > 0),
+                              funnel=res.funnel)
+                for j in range(len(group))]
 
     def flush(self, k: int) -> list[ReverseResult]:
         """Answer every pending ticket; results in submission order."""

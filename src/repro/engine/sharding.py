@@ -261,8 +261,15 @@ def _flat_candidates(items, item_ids, item_mask, codes, ucodes, queries,
     shape-identical at every Q, so every executable computes identical
     rows; the N-axis work inside each step stays fully vectorized, and Q
     is a micro-batch on the serving path.
+
+    Each stage runs under a ``jax.named_scope`` — ``kmips.scan`` (Hamming
+    scores and mask), ``kmips.select`` (the top-n_cand), ``kmips.rerank``
+    (gather, inner products, top-k; all of the exact scan) — so a device
+    profile can time them apart (``serving.op_scopes``). Scopes are
+    metadata only: the arithmetic is unchanged.
     """
     if scan == "exact":
+        @jax.named_scope("kmips.rerank")
         def one_exact(q):
             ips = jnp.where(item_mask, items @ q, _NEG)
             vals, pos = jax.lax.top_k(ips, k)
@@ -271,13 +278,16 @@ def _flat_candidates(items, item_ids, item_mask, codes, ucodes, queries,
 
     def one_sketch(args):
         uc, q = args
-        dist = kops.hamming_scores(uc[None], codes)[0]    # (N,)
-        dist = jnp.where(item_mask, dist, _BIG_HAMMING)
-        _, cand = jax.lax.top_k(-dist, n_cand)            # (n_cand,)
-        ips = jnp.take(items, cand, axis=0) @ q
-        ips = jnp.where(jnp.take(item_mask, cand), ips, _NEG)
-        vals, pos = jax.lax.top_k(ips, k)
-        return vals, jnp.take(jnp.take(item_ids, cand), pos)
+        with jax.named_scope("kmips.scan"):
+            dist = kops.hamming_scores(uc[None], codes)[0]    # (N,)
+            dist = jnp.where(item_mask, dist, _BIG_HAMMING)
+        with jax.named_scope("kmips.select"):
+            _, cand = jax.lax.top_k(-dist, n_cand)            # (n_cand,)
+        with jax.named_scope("kmips.rerank"):
+            ips = jnp.take(items, cand, axis=0) @ q
+            ips = jnp.where(jnp.take(item_mask, cand), ips, _NEG)
+            vals, pos = jax.lax.top_k(ips, k)
+            return vals, jnp.take(jnp.take(item_ids, cand), pos)
     return jax.lax.map(one_sketch, (ucodes, queries))
 
 
