@@ -104,8 +104,7 @@ def main():
     t0 = time.time()
     for i in range(args.requests):
         server.submit(u[i])
-    results = server.flush(args.k, n_cand=64)
-    jax.block_until_ready(results[-1].values)
+    results = server.flush(args.k, n_cand=64)      # answers on the host
     t_sah = time.time() - t0
 
     sids = jnp.stack([r.ids for r in results])

@@ -56,7 +56,8 @@ accuracy knob.
 Tracing: ``_flush_batch`` of both servers opens the host spans
 ``rk.flush.pad`` (stack and pad), ``rk.flush.launch`` (the compiled
 dispatch, or the engine call), ``rk.flush.merge`` (the delta fold-in, only
-with staged rows) and ``rk.flush.split`` (per-ticket results) as
+with staged rows) and ``rk.flush.split`` (per-ticket results: for the
+forward server, the batch's one device-to-host copy and its row views) as
 ``jax.profiler.TraceAnnotation``s, nested in the runtime's ``rk.flush``.
 On the device, the forward stages run under ``jax.named_scope``s
 (``kmips.hash``, ``.scan``, ``.select``, ``.rerank``, ``.merge``), and
@@ -159,10 +160,14 @@ class ServingState(NamedTuple):
 class ServeResult(NamedTuple):
     """One served query's answer (values descending; ids in the caller's
     corpus row space — for artifact-backed servers that is artifact id
-    space: base rows keep their ids, staged row j is n_base + j)."""
+    space: base rows keep their ids, staged row j is n_base + j).
 
-    values: jnp.ndarray
-    ids: jnp.ndarray
+    ``values`` and ``ids`` are host ``np.ndarray``s: read-only row views
+    of the one device-to-host copy ``_flush_batch`` makes of its whole
+    micro-batch, with the dispatch's dtypes."""
+
+    values: np.ndarray
+    ids: np.ndarray
     k: int
 
 
@@ -639,6 +644,9 @@ class RetrievalServer(_TicketQueue):
                     n_base=self.artifact.n_base,
                     scan_precision=self.config.scan_precision)
         with TraceAnnotation("rk.flush.split"):
+            # one device-to-host copy of the whole batch; each ticket's
+            # answer is a read-only row view of it
+            vals, ids = jax.device_get((vals, ids))
             return [ServeResult(vals[j], ids[j], k)
                     for j in range(len(group))]
 

@@ -10,6 +10,10 @@ stripping. The padding checks here are the hypothesis-free mirrors of
 tests/test_core_properties.py, so they run on minimal installs too.
 """
 
+import os
+import subprocess
+import sys
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -18,14 +22,14 @@ import pytest
 from repro.core import sa_alsh, sah
 from repro.data import synthetic
 from repro.dist.policy import NO_SHARDING
-from repro.engine import (RetrievalServer, RkMIPSEngine, ServingCache,
-                          build_serving_state, get_config)
+from repro.engine import (IndexArtifact, RetrievalServer, RkMIPSEngine,
+                          ServingCache, ServingRuntime, build_serving_state,
+                          get_config)
 from repro.engine import sharding as eng_sharding
 from repro.kernels import ops as kops
 
 
-@pytest.fixture(scope="module")
-def corpus():
+def _corpus():
     key = jax.random.PRNGKey(11)
     ki, kq = jax.random.split(key)
     items, _ = synthetic.recommendation_data(ki, 509, 16, 24)   # prime n
@@ -33,9 +37,18 @@ def corpus():
     return items, queries
 
 
+def _server_cfg():
+    return get_config("sah").replace(tile=128, n_bits=64, serve_batch_size=4)
+
+
+@pytest.fixture(scope="module")
+def corpus():
+    return _corpus()
+
+
 @pytest.fixture(scope="module")
 def server_cfg():
-    return get_config("sah").replace(tile=128, n_bits=64, serve_batch_size=4)
+    return _server_cfg()
 
 
 def test_microbatch_matches_one_at_a_time_engine_kmips(corpus, server_cfg):
@@ -448,3 +461,122 @@ def test_result_mapping_drops_phantom_ids():
     out = sah.predictions_to_original(bad, all_yes, 17)
     ref = sah.predictions_to_original(idx, jnp.ones((idx.n_users,), bool), 17)
     np.testing.assert_array_equal(np.asarray(out), np.asarray(ref))
+
+
+# ---------------------------------------------------------------------------
+# One device-to-host copy per forward micro-batch: every answer is a
+# read-only numpy row of it, on every path that serves through
+# ``_flush_batch``.
+# ---------------------------------------------------------------------------
+
+def _host_copy_server(case: str, items, policy=NO_SHARDING):
+    """(server, tickets in one micro-batch) for one host-copy case."""
+    cfg = _server_cfg()
+    key = jax.random.PRNGKey(4)
+    if case == "delta":
+        art = IndexArtifact.build(items, None, key,
+                                  config=cfg.replace(delta_capacity=8))
+        # staged rows scaled up so they rank, and deletes on both sides
+        art = art.insert_items(items[:3] * 1.5).delete_items(
+            [0, 7, art.n_base + 1])
+        return RetrievalServer.from_artifact(art), 3
+    if case == "rung":
+        return RetrievalServer(items, key, policy=policy,
+                               config=cfg.replace(serve_buckets=(2,))), 2
+    return (RetrievalServer(items, key, config=cfg, policy=policy),
+            3 if case == "partial" else 4)
+
+
+def _check_host_answers(srv: RetrievalServer, queries, k: int) -> None:
+    """``queries`` (one micro-batch) served by ``_flush_batch`` at their
+    ladder rung, by the synchronous ``flush`` (full-batch padding), by
+    ``kmips`` and by a ``ServingRuntime``: every answer's values and ids
+    are read-only ``np.ndarray`` rows, bitwise the matching row of a
+    direct ``_dispatch`` call (delta-merged where rows are staged)."""
+    n = queries.shape[0]
+    pad_to = srv.bucket_for(n)
+    qs = jnp.concatenate([queries, jnp.zeros((pad_to - n, queries.shape[1]),
+                                             queries.dtype)])
+    state = srv.cache.get(srv.config)
+    vals, ids = srv._dispatch(state.items, state.item_ids,
+                              srv._masked_item_mask(state), state.codes,
+                              state.proj_q, qs, k=k,
+                              n_cand=srv.config.n_cand, scan=srv.config.scan)
+    if srv._delta[0] is not None:
+        vals, ids = srv._merge(vals, ids, qs, *srv._delta, k=k,
+                               n_base=srv.artifact.n_base,
+                               scan_precision=srv.config.scan_precision)
+    want = (np.asarray(vals), np.asarray(ids))
+
+    answers = {"rung": srv._flush_batch(list(queries), k, pad_to=pad_to)}
+    srv.submit(queries)
+    answers["flush"] = srv.flush(k)
+    answers["kmips"] = [srv.kmips(queries[0], k)]
+    rt = ServingRuntime(srv, k=k)
+    try:
+        answers["runtime"] = [t.result(timeout=60)
+                              for t in rt.submit(queries)]
+    finally:
+        rt.close()
+    for path, res in answers.items():
+        assert len(res) == (1 if path == "kmips" else n), path
+        for j, r in enumerate(res):
+            assert r.k == k
+            for got, ref in zip((r.values, r.ids), want):
+                assert type(got) is np.ndarray, (path, type(got))
+                assert not got.flags.writeable, path
+                assert got.dtype == ref.dtype, path
+                np.testing.assert_array_equal(got, ref[j], err_msg=path)
+
+
+_MESH_HOST_COPY_SCRIPT = r"""
+import os, sys
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
+sys.path.insert(0, "tests")
+import jax
+from repro.dist.policy import ShardingPolicy
+import test_serving as ts
+
+policy = ShardingPolicy(mesh=jax.make_mesh((2, 4), ("data", "model")),
+                        rules={})
+items, queries = ts._corpus()
+srv, n = ts._host_copy_server("full", items, policy=policy)
+assert srv.cache.get(srv.config).items.sharding.num_devices == 8
+ts._check_host_answers(srv, queries[:n], 5)
+print("MESH HOST COPY OK")
+"""
+
+
+@pytest.mark.parametrize("case", ["full", "partial", "rung", "delta",
+                                  "mesh8"])
+def test_forward_answers_are_host_rows_of_one_copy(corpus, case):
+    """Forward answers are read-only host rows, bitwise the direct
+    dispatch's, on a full batch, a zero-padded partial batch, a ladder
+    rung, an artifact with staged inserts and deletes, and the 8-device
+    CPU mesh (subprocess: the device count is fixed at JAX start-up)."""
+    if case == "mesh8":
+        env = dict(os.environ, PYTHONPATH="src")
+        out = subprocess.run([sys.executable, "-c", _MESH_HOST_COPY_SCRIPT],
+                             env=env, capture_output=True, text=True,
+                             timeout=600,
+                             cwd=os.path.dirname(os.path.dirname(__file__)))
+        assert out.returncode == 0, out.stdout + "\n" + out.stderr
+        assert "MESH HOST COPY OK" in out.stdout
+        return
+    items, queries = corpus
+    srv, n = _host_copy_server(case, items)
+    if case == "delta":
+        assert srv._delta[0] is not None and srv._deleted is not None
+    _check_host_answers(srv, queries[:n], 5)
+
+
+def test_full_batch_flush_uploads_nothing(corpus, server_cfg):
+    """A full-batch forward ``_flush_batch`` sends nothing from the host to
+    the device: no per-ticket device op, so no row index to upload."""
+    items, queries = corpus
+    srv = RetrievalServer(items, jax.random.PRNGKey(4), config=server_cfg)
+    group = [queries[j] for j in range(srv.batch_size)]
+    srv._flush_batch(group, 5)            # compile outside the guard
+    with jax.transfer_guard_host_to_device("disallow"):
+        res = srv._flush_batch(group, 5)
+    assert len(res) == srv.batch_size
