@@ -54,7 +54,8 @@ and padding is dead, so batching is a latency/throughput knob, never an
 accuracy knob.
 
 Tracing: ``_flush_batch`` of both servers opens the host spans
-``rk.flush.pad`` (stack and pad), ``rk.flush.launch`` (the compiled
+``rk.flush.pad`` (stack and pad: for the forward server, one launch of
+the compiled ``_pad_batch``), ``rk.flush.launch`` (the compiled
 dispatch, or the engine call), ``rk.flush.merge`` (the delta fold-in, only
 with staged rows) and ``rk.flush.split`` (per-ticket results: for the
 forward server, the batch's one device-to-host copy and its row views) as
@@ -66,6 +67,7 @@ On the device, the forward stages run under ``jax.named_scope``s
 
 from __future__ import annotations
 
+import functools
 import re
 import threading
 from collections import OrderedDict
@@ -108,6 +110,21 @@ def instruction_scopes(hlo_text: str) -> tuple[str, dict[str, str]]:
         inner = [part for part in path if part.startswith(_SCOPE_PREFIX)]
         scopes[name] = inner[-1] if inner else ""
     return (module.group(1) if module else ""), scopes
+
+
+@functools.partial(jax.jit, static_argnames=("pad_to",))
+def _pad_batch(rows: tuple, *, pad_to: int) -> jnp.ndarray:
+    """A forward micro-batch's (d,) query rows -> the (pad_to, d) batch
+    the dispatch takes: stacked, then zero rows after the live ones.
+
+    One launch of one XLA program per (group length, dtype, pad_to),
+    where eager ops would launch and allocate per row and upload the
+    zero fill; ``jnp.stack``'s dtype promotion and placement, so answers
+    are bitwise those of the eager stack and pad. Module-level, so every
+    ``RetrievalServer`` shares its executables; ``warmup`` compiles one
+    per group length at each rung (DESIGN.md SS8, SS14)."""
+    qs = jnp.stack(rows)
+    return jnp.pad(qs, ((0, pad_to - qs.shape[0]), (0, 0)))
 
 
 def _record_scopes(compiled) -> None:
@@ -626,11 +643,7 @@ class RetrievalServer(_TicketQueue):
             raise ValueError(f"group of {len(group)} does not fit "
                              f"pad_to={batch}")
         with TraceAnnotation("rk.flush.pad"):
-            qs = jnp.stack(group)
-            if len(group) < batch:
-                qs = jnp.concatenate(
-                    [qs, jnp.zeros((batch - len(group), qs.shape[1]),
-                                   qs.dtype)])
+            qs = _pad_batch(tuple(group), pad_to=batch)
         with TraceAnnotation("rk.flush.launch"):
             vals, ids = self._dispatch(state.items, state.item_ids,
                                        self._masked_item_mask(state),
@@ -666,7 +679,10 @@ class RetrievalServer(_TicketQueue):
         live path calls (``compile_count`` counts these warmup traces
         too), and the populated jit cache is what the live calls hit.
         Each compiled cell's instruction scopes are recorded for
-        ``op_scopes``.
+        ``op_scopes``. The stack-and-pad program ``_pad_batch`` is
+        compiled too, for every group length up to each rung; it is not
+        a dispatch cell, so neither the returned count nor
+        ``compile_count`` includes it.
         """
         state = self.cache.get(self.config)
         mask = self._masked_item_mask(state)
@@ -683,7 +699,10 @@ class RetrievalServer(_TicketQueue):
         # churn will hit — staging the first insert must not trace
         art = self.artifact
         cells = 0
+        row = jax.ShapeDtypeStruct((d,), state.items.dtype)
         for b in buckets:
+            for n in range(1, b + 1):
+                _pad_batch.lower((row,) * n, pad_to=b).compile()
             qs = jnp.zeros((b, d), state.items.dtype)
             for k in ks:
                 for nc in n_cands:

@@ -25,6 +25,7 @@ from repro.dist.policy import NO_SHARDING
 from repro.engine import (IndexArtifact, RetrievalServer, RkMIPSEngine,
                           ServingCache, ServingRuntime, build_serving_state,
                           get_config)
+from repro.engine import serving
 from repro.engine import sharding as eng_sharding
 from repro.kernels import ops as kops
 
@@ -469,6 +470,11 @@ def test_result_mapping_drops_phantom_ids():
 # ``_flush_batch``.
 # ---------------------------------------------------------------------------
 
+# a zero-padded partial batch at every group length below the full batch
+# (serve_batch_size 4): one stack-and-pad program each
+_PARTIAL = {"partial1": 1, "partial2": 2, "partial": 3}
+
+
 def _host_copy_server(case: str, items, policy=NO_SHARDING):
     """(server, tickets in one micro-batch) for one host-copy case."""
     cfg = _server_cfg()
@@ -484,7 +490,7 @@ def _host_copy_server(case: str, items, policy=NO_SHARDING):
         return RetrievalServer(items, key, policy=policy,
                                config=cfg.replace(serve_buckets=(2,))), 2
     return (RetrievalServer(items, key, config=cfg, policy=policy),
-            3 if case == "partial" else 4)
+            _PARTIAL.get(case, cfg.serve_batch_size))
 
 
 def _check_host_answers(srv: RetrievalServer, queries, k: int) -> None:
@@ -547,13 +553,14 @@ print("MESH HOST COPY OK")
 """
 
 
-@pytest.mark.parametrize("case", ["full", "partial", "rung", "delta",
-                                  "mesh8"])
+@pytest.mark.parametrize("case", ["full", "partial1", "partial2", "partial",
+                                  "rung", "delta", "mesh8"])
 def test_forward_answers_are_host_rows_of_one_copy(corpus, case):
     """Forward answers are read-only host rows, bitwise the direct
-    dispatch's, on a full batch, a zero-padded partial batch, a ladder
-    rung, an artifact with staged inserts and deletes, and the 8-device
-    CPU mesh (subprocess: the device count is fixed at JAX start-up)."""
+    dispatch's, on a full batch, a zero-padded partial batch of every
+    length below it, a ladder rung, an artifact with staged inserts and
+    deletes, and the 8-device CPU mesh (subprocess: the device count is
+    fixed at JAX start-up)."""
     if case == "mesh8":
         env = dict(os.environ, PYTHONPATH="src")
         out = subprocess.run([sys.executable, "-c", _MESH_HOST_COPY_SCRIPT],
@@ -570,13 +577,64 @@ def test_forward_answers_are_host_rows_of_one_copy(corpus, case):
     _check_host_answers(srv, queries[:n], 5)
 
 
-def test_full_batch_flush_uploads_nothing(corpus, server_cfg):
-    """A full-batch forward ``_flush_batch`` sends nothing from the host to
-    the device: no per-ticket device op, so no row index to upload."""
+@pytest.mark.parametrize("n,buckets", [(1, ()), (3, ()), (4, ()),
+                                       (2, (2,))],
+                         ids=["1", "3", "full", "rung"])
+def test_full_batch_flush_uploads_nothing(corpus, server_cfg, n, buckets):
+    """A forward ``_flush_batch`` sends nothing from the host to the
+    device, full batch, partial batch or ladder rung alike: no per-ticket
+    device op, so no row index to upload, and the zero padding is a
+    constant of the compiled stack-and-pad program."""
+    items, queries = corpus
+    srv = RetrievalServer(items, jax.random.PRNGKey(4),
+                          config=server_cfg.replace(serve_buckets=buckets))
+    group = [queries[j] for j in range(n)]
+    pad_to = srv.bucket_for(n)
+    srv._flush_batch(group, 5, pad_to=pad_to)   # compile outside the guard
+    with jax.transfer_guard_host_to_device("disallow"):
+        res = srv._flush_batch(group, 5, pad_to=pad_to)
+    assert len(res) == n
+
+
+def test_warm_flush_compiles_nothing_at_any_group_size(corpus, server_cfg,
+                                                       fresh_compiles):
+    """After ``warmup``, a forward ``_flush_batch`` of every group size
+    1..batch_size runs only executables that exist: no backend compile,
+    the stack-and-pad program's included."""
     items, queries = corpus
     srv = RetrievalServer(items, jax.random.PRNGKey(4), config=server_cfg)
-    group = [queries[j] for j in range(srv.batch_size)]
-    srv._flush_batch(group, 5)            # compile outside the guard
-    with jax.transfer_guard_host_to_device("disallow"):
-        res = srv._flush_batch(group, 5)
-    assert len(res) == srv.batch_size
+    assert srv.warmup((5,)) == 1
+    compiles = []
+
+    def on_event(event, duration, **_):
+        if event == "/jax/core/compile/backend_compile_duration":
+            compiles.append(event)
+    jax.monitoring.register_event_duration_secs_listener(on_event)
+    try:
+        for n in range(1, srv.batch_size + 1):
+            srv._flush_batch([queries[j] for j in range(n)], 5)
+            assert not compiles, f"group of {n} compiled"
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_event)
+
+
+@pytest.mark.parametrize("n", [1, 3, 4])
+@pytest.mark.parametrize("dtypes", [("float32",), ("bfloat16",),
+                                    ("bfloat16", "float32")],
+                         ids=["float32", "bfloat16", "mixed"])
+def test_pad_batch_is_the_eager_stack_and_pad(corpus, n, dtypes):
+    """``serving._pad_batch`` returns bitwise what the eager stack and
+    zero pad gives — values, dtype (promotion included), shape and
+    placement — for a partial and a full batch."""
+    _, queries = corpus
+    rows = [queries[j].astype(dtypes[j % len(dtypes)]) for j in range(n)]
+    got = serving._pad_batch(tuple(rows), pad_to=4)
+    stacked = jnp.stack(rows)
+    want = jnp.concatenate(
+        [stacked, jnp.zeros((4 - n, stacked.shape[1]), stacked.dtype)])
+    assert got.dtype == want.dtype
+    assert got.shape == want.shape == (4, queries.shape[1])
+    assert got.sharding == want.sharding
+    assert got.committed == want.committed
+    np.testing.assert_array_equal(np.asarray(got).view(np.uint8),
+                                  np.asarray(want).view(np.uint8))

@@ -211,20 +211,6 @@ def test_linger_counts_only_a_held_partial_batch(workload, artifact,
 # -- (3) device scopes ---------------------------------------------------
 
 
-@pytest.fixture
-def fresh_compiles():
-    """Compile without JAX's persistent cache: its keys leave metadata
-    out, so an entry another test wrote before the scopes existed would
-    come back without them."""
-    from jax.experimental.compilation_cache import compilation_cache
-    was = jax.config.jax_enable_compilation_cache
-    jax.config.update("jax_enable_compilation_cache", False)
-    compilation_cache.reset_cache()
-    yield
-    jax.config.update("jax_enable_compilation_cache", was)
-    compilation_cache.reset_cache()
-
-
 def test_every_rung_carries_the_forward_scopes(monkeypatch, artifact,
                                                fresh_compiles):
     monkeypatch.setattr(serving, "_SCOPES_SEEN", {})
